@@ -67,6 +67,9 @@ print("RESULT", np.median(ts), int(st.bp_rounds), int(st.tiles_processed),
 def _run_child(ndev, mesh_shape, size, sparse=False, tiled=False, tile=128,
                iters=3):
     env = dict(os.environ)
+    # The children emulate devices on the host CPU by design; on a TPU host
+    # they must not try to take the chip this process may hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     code = _CHILD.format(mesh_shape=mesh_shape, size=size, sparse=sparse,

@@ -183,10 +183,10 @@ def serpentine_kernel_guard(records: list, n: int = 64):
     I = jnp.asarray(np.pad(np.asarray(state["I"]), 1, constant_values=neut))
     valid = jnp.asarray(np.pad(np.ones((n, n), bool), 1))
     d, di = morph_tile_solve(J, I, valid, connectivity=8,
-                             max_iters=(n + 2) ** 2, interpret=True)
+                             max_iters=(n + 2) ** 2)
     q, qi, spills = morph_tile_solve_queued(J, I, valid, connectivity=8,
                                             max_iters=(n + 2) ** 2,
-                                            queue_capacity=16, interpret=True)
+                                            queue_capacity=16)
     assert np.array_equal(np.asarray(d), np.asarray(q)), \
         "queued kernel diverged from the dense fixed point"
     assert int(qi) <= int(di), \

@@ -12,6 +12,8 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro.core.compile_cache import enable_persistent_cache
+
 # The canonical workload builders live in the package now
 # (repro.ops.workloads) so calibration and the selection-regression tests
 # rebuild the exact inputs these benchmarks time; re-exported here so bench
@@ -61,7 +63,12 @@ def bench_argparser(default_json: str, *, size: int = 512,
                     smoke_help: Optional[str] = None) -> argparse.ArgumentParser:
     """The shared benchmark CLI: ``--size``, ``--json [PATH]`` and (when
     ``smoke_help`` is given) the ``--smoke`` CI profile flag.  Callers add
-    their bench-specific arguments on the returned parser."""
+    their bench-specific arguments on the returned parser.
+
+    Every bench's ``__main__`` builds this parser before it compiles
+    anything, so this is also where the benches turn on JAX's persistent
+    compilation cache (:func:`repro.core.compile_cache.enable_persistent_cache`)."""
+    enable_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", type=int, default=size)
     ap.add_argument("--json", nargs="?", const=default_json, default=None,

@@ -436,7 +436,7 @@ def _pallas_drain_points(op, spec, state, tiles, interpret: bool,
 def run_calibration(ops: Optional[Sequence[str]] = None,
                     smoke: bool = False,
                     save: bool = True,
-                    interpret: bool = True,
+                    interpret: Optional[bool] = None,
                     cal_size: Optional[int] = None,
                     dense_sizes: Optional[Sequence[int]] = None,
                     verbose: bool = False) -> CalibrationProfile:
@@ -462,7 +462,10 @@ def run_calibration(ops: Optional[Sequence[str]] = None,
             "CostModel when no profile exists")
     from repro import solve as S
     from repro.core import autotune_disk
+    from repro.kernels import resolve_interpret
     from repro.ops import get_op, list_ops
+
+    interpret = resolve_interpret(interpret)
 
     def say(msg: str) -> None:
         if verbose:

@@ -20,12 +20,38 @@ Keys are plain hashable tuples.  By convention the first element is a short
 string naming the builder site (``"tiled-drain"``, ``"sharded-fn"``, ...)
 and the second the op class, so invalidation by op never has to guess at
 key layouts — but any hashable tuple works.
+
+Across processes, :func:`enable_persistent_cache` points JAX's own
+persistent compilation cache at a fixed directory.  Entry points
+(``chip_smoke.py``, the benchmarks) call it; importing a library module
+never does.
 """
 
 from __future__ import annotations
 
+import os
+import pathlib
 import threading
 from typing import Any, Callable, Dict, Optional, Tuple
+
+# <checkout>/.jax_cache: a fixed path (the path is part of the cache key, so
+# a directory named after a tmp dir, a pid or the time would never hit).
+PERSISTENT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    this sets nothing.  Otherwise the cache goes to
+    :data:`PERSISTENT_CACHE_DIR`.  Call before the first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(PERSISTENT_CACHE_DIR))
+    return str(PERSISTENT_CACHE_DIR)
 
 _LOCK = threading.RLock()
 _CACHE: Dict[tuple, Any] = {}

@@ -81,16 +81,6 @@ class ShardStats(NamedTuple):
     per_device_tiles: jnp.ndarray  # (nrows, ncols) per-device drain counts
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """Version-compat wrapper: jax.shard_map (new) vs jax.experimental (old)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _shift_axis(x, axis_name: str, direction: int, fill, mesh_axis_size: int):
     """ppermute x to the neighbor `direction` steps along `axis_name`.
 
@@ -203,8 +193,7 @@ def run_sharded(op: PropagationOp, state, mesh: Mesh,
     again with the same (op, mesh, state signature, knobs) reuses the
     executable.  ``donate=True`` additionally donates the input buffers to
     the compiled call (pass it only when the caller owns a private copy,
-    e.g. after padding to a mesh multiple); donation is skipped on CPU,
-    which does not implement it.
+    e.g. after padding to a mesh multiple).
     """
     row_ax, col_ax = axes
     nrows, ncols = mesh.shape[row_ax], mesh.shape[col_ax]
@@ -436,10 +425,11 @@ def run_sharded(op: PropagationOp, state, mesh: Mesh,
     device_fn = device_fn_dense if tile is None else device_fn_tiled
 
     def build():
-        fn = shard_map_compat(device_fn, mesh, (spec,),
-                              (spec, P(), (P(), P(), P()), P(row_ax, col_ax)))
-        dn = (0,) if donate and jax.default_backend() != "cpu" else ()
-        return jax.jit(fn, donate_argnums=dn)
+        fn = jax.shard_map(
+            device_fn, mesh=mesh, in_specs=(spec,),
+            out_specs=(spec, P(), (P(), P(), P()), P(row_ax, col_ax)),
+            check_vma=False)
+        return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
     key = ("sharded-fn", op, _mesh_fingerprint(mesh), axes,
            _state_signature(state), tile, queue_capacity, drain_batch,

@@ -48,10 +48,20 @@ class SchedulerStats:
     requeues_from_failures: int = 0
     tiles_requeued: int = 0        # unconverged (partial) drains re-queued
     per_worker: Dict[int, int] = field(default_factory=dict)
+    device_tiles: int = 0          # of tiles_processed, drained by DeviceWorkers
+    # repr of every exception a worker died on, except the injected ones
+    # (InjectedFailure): a device drain that fails to compile lands here
+    # instead of silently leaving the queue to the host threads.
+    worker_errors: List[str] = field(default_factory=list)
     # True iff run() gave up with work still queued (every survivor wave
     # died, max_survivor_waves exhausted): the state is NOT at its fixed
     # point and must not be treated as one.
     incomplete: bool = False
+
+
+class InjectedFailure(RuntimeError):
+    """Raised by the ``fail_worker`` fault-injection hook; the one worker
+    death that is expected and therefore not recorded as an error."""
 
 
 class ChunkPolicy:
@@ -314,7 +324,8 @@ class TileScheduler:
             if all(0 <= c < g for c, g in zip(nb, self.grid)):
                 self._push(nb)
 
-    def _commit(self, tid, block, unconverged: bool, wid: int):
+    def _commit(self, tid, block, unconverged: bool, wid: int,
+                device: bool = False):
         """Write one drained block back and update marks/stats (lock held)."""
         edges = self._write_back(tid, block)
         self._mark_neighbors(tid, edges)
@@ -325,7 +336,18 @@ class TileScheduler:
             self._push(tid)
             self.stats.tiles_requeued += 1
         self.stats.tiles_processed += 1
+        self.stats.device_tiles += device
         self.stats.per_worker[wid] = self.stats.per_worker.get(wid, 0) + 1
+
+    def _record_death(self, who: str, err: Exception) -> None:
+        """Record and warn about a worker killed by a real exception."""
+        if isinstance(err, InjectedFailure):
+            return
+        with self._lock:
+            self.stats.worker_errors.append(f"{who}: {err!r}")
+        warnings.warn(f"TileScheduler {who} died on {err!r}; its tiles were "
+                      "re-queued for the surviving workers", RuntimeWarning,
+                      stacklevel=2)
 
     def _should_fail(self, wid: int, n_done: int) -> bool:
         """Fault-injection hook: kill worker ``fail_worker`` (or every
@@ -367,14 +389,14 @@ class TileScheduler:
             block = self._slice_block(tid)
             try:
                 if self._should_fail(wid, n_done):
-                    raise RuntimeError(f"injected failure on worker {wid}")
+                    raise InjectedFailure(f"injected failure on worker {wid}")
                 t0 = time.perf_counter()
                 new_block, info = self.tile_fn(block)
                 self.chunk_policy.observe_host(time.perf_counter() - t0)
                 with self._lock:
                     self._commit(tid, new_block, info is True, wid)
                     n_done += 1
-            except Exception:
+            except Exception as e:
                 # Fault tolerance: re-queue the tile; state untouched (tiles
                 # are idempotent under IWPP's monotone commutative updates).
                 with self._lock:
@@ -382,6 +404,7 @@ class TileScheduler:
                     self.stats.requeues_from_failures += 1
                     self._inflight -= 1
                     self._done.notify_all()
+                self._record_death(f"host worker {wid}", e)
                 return  # worker dies; remaining workers pick up the slack
             with self._lock:
                 self._inflight -= 1
@@ -436,10 +459,10 @@ class TileScheduler:
                 t0 = time.perf_counter()
                 try:
                     if self._should_fail(wid, n_done):
-                        raise RuntimeError(
+                        raise InjectedFailure(
                             f"injected failure on device worker {wid}")
                     results = self._drain_chunk(dev, blocks)
-                except Exception:
+                except Exception as e:
                     with self._lock:
                         # Re-queue this group and every unstarted one; the
                         # groups already committed stay committed (monotone
@@ -450,12 +473,13 @@ class TileScheduler:
                         self.stats.requeues_from_failures += len(rest)
                         self._inflight -= len(rest)
                         self._done.notify_all()
+                    self._record_death(f"device worker {wid} ({dev.name})", e)
                     return  # device worker dies; survivors take over
                 self.chunk_policy.observe_device(
                     (time.perf_counter() - t0) / len(gtids))
                 with self._lock:
                     for t, (nb, unconv) in zip(gtids, results):
-                        self._commit(t, nb, unconv, wid)
+                        self._commit(t, nb, unconv, wid, device=True)
                     n_done += len(gtids)
                     self._inflight -= len(gtids)
                     self._done.notify_all()

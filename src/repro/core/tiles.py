@@ -477,20 +477,14 @@ def drain(plan: TiledPlan, run_state: TiledRunState) -> TiledRunState:
     return jax.lax.while_loop(cond, lambda rs: step(plan, rs), run_state)
 
 
-def _donate_argnums() -> tuple:
-    # CPU XLA has no buffer donation — requesting it only produces a
-    # "donated buffers were not usable" warning per call.
-    return () if jax.default_backend() == "cpu" else (0,)
-
-
 def drain_fn(plan: TiledPlan) -> Callable:
     """The compiled re-entrant drain for ``plan``: one build per plan via
-    the shared compile cache, carrier donated on backends that support it.
+    the shared compile cache, carrier donated (its buffers are the engine's
+    own padded copies, never the caller's input).
     ``drain_fn(plan)(run_state) -> run_state``."""
     return compile_cache.get(
         ("tiled-drain", plan.op, plan),
-        lambda: jax.jit(lambda rs: drain(plan, rs),
-                        donate_argnums=_donate_argnums()))
+        lambda: jax.jit(lambda rs: drain(plan, rs), donate_argnums=(0,)))
 
 
 def finalize(plan: TiledPlan, run_state: TiledRunState, ref_state,

@@ -23,16 +23,24 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.geometry import ravel_index, unravel_index
 from repro.core.pattern import offsets_for
 from repro.edt.ref import SENTINEL
+from repro.kernels import resolve_interpret
 from repro.kernels.queue import fit_seed as _fit_seed
-from repro.kernels.queue import queued_fixed_point
+from repro.kernels.queue import queued_fixed_point, queued_interpret
+
+
+# The 3-D conn26 kernel at T=32 (34³ blocks, lanes padded 34 -> 128) needs
+# ~39 MiB of scoped VMEM, past Mosaic's 16 MiB default; a v5e has 128 MiB.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 * 2**20)
 
 
 def _full(shape):
@@ -101,21 +109,17 @@ def _make_kernel(connectivity, max_iters: int, batched: bool = False):
 
         vr, _, iters = jax.lax.while_loop(
             cond, body, (tuple(vr), jnp.bool_(True), jnp.int32(0)))
-        if batched:
-            for o_ref, p in zip(outs[:ndim], vr):
-                o_ref[0] = p
-            outs[ndim][0, 0, 0] = iters
-        else:
-            for o_ref, p in zip(outs[:ndim], vr):
-                o_ref[...] = p
-            outs[ndim][0, 0] = iters
+        for o_ref, p in zip(outs[:ndim], vr):
+            o_ref[...] = p.reshape(o_ref.shape)
+        # Full-block store: Mosaic cannot store a scalar to VMEM.
+        outs[ndim][...] = jnp.full(outs[ndim].shape, iters)
 
     return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("connectivity", "max_iters", "interpret"))
 def edt_tile_solve_nd(vr, valid, coords, *, connectivity=8,
-                      max_iters: int = 1024, interpret: bool = True):
+                      max_iters: int = 1024, interpret: Optional[bool] = None):
     """Drain one EDT halo block, any spatial rank.
 
     ``vr``/``coords``: (ndim, *spatial) stacked pointer/coordinate planes;
@@ -131,13 +135,14 @@ def edt_tile_solve_nd(vr, valid, coords, *, connectivity=8,
         out_shape=out_shape,
         in_specs=[_full(shp)] * (2 * ndim + 1),
         out_specs=tuple([_full(shp)] * ndim) + (_full((1, 1)),),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        compiler_params=_COMPILER_PARAMS,
     )(*[vr[i] for i in range(ndim)], valid, *[coords[i] for i in range(ndim)])
     return jnp.stack(outs[:ndim]), outs[ndim][0, 0]
 
 
 def edt_tile_solve(vr_r, vr_c, valid, row, col, *, connectivity=8,
-                   max_iters: int = 1024, interpret: bool = True):
+                   max_iters: int = 1024, interpret: Optional[bool] = None):
     """Drain one (T+2, T+2) EDT halo block.  Returns (vr_r, vr_c, iters) —
     the historical 2D spelling of :func:`edt_tile_solve_nd`."""
     o, iters = edt_tile_solve_nd(
@@ -257,16 +262,10 @@ def _make_queued_kernel(connectivity, max_iters: int, capacity: int,
             dense_round, queued_round, tuple(vr),
             max_iters=max_iters, capacity=capacity,
             initial_queue=initial_queue)
-        if batched:
-            for o_ref, p in zip(out_refs, vr):
-                o_ref[0] = p
-            iters_ref[0, 0, 0] = iters
-            spills_ref[0, 0, 0] = spills
-        else:
-            for o_ref, p in zip(out_refs, vr):
-                o_ref[...] = p
-            iters_ref[0, 0] = iters
-            spills_ref[0, 0] = spills
+        for o_ref, p in zip(out_refs, vr):
+            o_ref[...] = p.reshape(o_ref.shape)
+        iters_ref[...] = jnp.full(iters_ref.shape, iters)
+        spills_ref[...] = jnp.full(spills_ref.shape, spills)
 
     return kernel
 
@@ -281,7 +280,7 @@ def _clip_capacity(queue_capacity: int, n: int, n_offsets: int) -> int:
                                              "queue_capacity", "interpret"))
 def edt_tile_solve_queued_nd(vr, valid, coords, seed=None, *, connectivity=8,
                              max_iters: int = 1024, queue_capacity: int = 64,
-                             interpret: bool = True):
+                             interpret: Optional[bool] = None):
     """Queued drain of one EDT halo block, any rank (DESIGN.md §2.5).
 
     ``vr``/``coords``: (ndim, *spatial).  Returns (vr_out, iters, spills) —
@@ -316,7 +315,8 @@ def edt_tile_solve_queued_nd(vr, valid, coords, seed=None, *, connectivity=8,
         out_shape=out_shape,
         in_specs=in_specs,
         out_specs=tuple([_full(shp)] * ndim) + (_full((1, 1)), _full((1, 1))),
-        interpret=interpret,
+        interpret=queued_interpret(interpret),
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
     return jnp.stack(outs[:ndim]), outs[ndim][0, 0], outs[ndim + 1][0, 0]
 
@@ -324,7 +324,7 @@ def edt_tile_solve_queued_nd(vr, valid, coords, seed=None, *, connectivity=8,
 def edt_tile_solve_queued(vr_r, vr_c, valid, row, col, seed=None, *,
                           connectivity=8,
                           max_iters: int = 1024, queue_capacity: int = 64,
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """Queued drain of one 2D EDT halo block — the historical spelling of
     :func:`edt_tile_solve_queued_nd`.  Returns (vr_r, vr_c, iters, spills)."""
     o, iters, spills = edt_tile_solve_queued_nd(
@@ -339,7 +339,7 @@ def edt_tile_solve_queued(vr_r, vr_c, valid, row, col, seed=None, *,
 def edt_tile_solve_queued_batched_nd(vr, valid, coords, seed=None, *,
                                      connectivity=8, max_iters: int = 1024,
                                      queue_capacity: int = 64,
-                                     interpret: bool = True):
+                                     interpret: Optional[bool] = None):
     """Queued drain of a (K, ndim, *spatial) EDT batch; one local queue per
     grid step.  Returns (vr_out, iters, spills) with (K,) counters.
 
@@ -372,7 +372,8 @@ def edt_tile_solve_queued_batched_nd(vr, valid, coords, seed=None, *,
         out_shape=out_shape,
         in_specs=in_specs,
         out_specs=tuple([blk] * ndim) + (scalar, scalar),
-        interpret=interpret,
+        interpret=queued_interpret(interpret),
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
     return (jnp.stack(outs[:ndim], axis=1),
             outs[ndim][:, 0, 0], outs[ndim + 1][:, 0, 0])
@@ -381,7 +382,7 @@ def edt_tile_solve_queued_batched_nd(vr, valid, coords, seed=None, *,
 def edt_tile_solve_queued_batched(vr_r, vr_c, valid, row, col, seed=None, *,
                                   connectivity=8, max_iters: int = 1024,
                                   queue_capacity: int = 64,
-                                  interpret: bool = True):
+                                  interpret: Optional[bool] = None):
     """Queued drain of a (K, T+2, T+2) 2D EDT batch — historical spelling of
     :func:`edt_tile_solve_queued_batched_nd`."""
     o, iters, spills = edt_tile_solve_queued_batched_nd(
@@ -394,7 +395,7 @@ def edt_tile_solve_queued_batched(vr_r, vr_c, valid, row, col, seed=None, *,
 
 @functools.partial(jax.jit, static_argnames=("connectivity", "max_iters", "interpret"))
 def edt_tile_solve_batched_nd(vr, valid, coords, *, connectivity=8,
-                              max_iters: int = 1024, interpret: bool = True):
+                              max_iters: int = 1024, interpret: Optional[bool] = None):
     """Drain a (K, ndim, *spatial) batch of EDT halo blocks concurrently.
 
     Returns (vr_out, iters) with iters shaped (K,); each grid step iterates
@@ -413,13 +414,14 @@ def edt_tile_solve_batched_nd(vr, valid, coords, *, connectivity=8,
         out_shape=out_shape,
         in_specs=[blk] * (2 * ndim + 1),
         out_specs=tuple([blk] * ndim) + (pl.BlockSpec((1, 1, 1), lambda k: (k, 0, 0)),),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        compiler_params=_COMPILER_PARAMS,
     )(*[vr[:, i] for i in range(ndim)], valid, *[coords[:, i] for i in range(ndim)])
     return jnp.stack(outs[:ndim], axis=1), outs[ndim][:, 0, 0]
 
 
 def edt_tile_solve_batched(vr_r, vr_c, valid, row, col, *, connectivity=8,
-                           max_iters: int = 1024, interpret: bool = True):
+                           max_iters: int = 1024, interpret: Optional[bool] = None):
     """Drain a (K, T+2, T+2) batch of 2D EDT halo blocks — historical
     spelling of :func:`edt_tile_solve_batched_nd`."""
     o, iters = edt_tile_solve_batched_nd(

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,8 +34,9 @@ from jax.experimental import pallas as pl
 
 from repro.core.geometry import ravel_index, unravel_index
 from repro.core.pattern import offsets_for
+from repro.kernels import resolve_interpret
 from repro.kernels.queue import fit_seed as _fit_seed
-from repro.kernels.queue import queued_fixed_point
+from repro.kernels.queue import queued_fixed_point, queued_interpret
 
 
 def _neutral(dtype):
@@ -96,19 +98,16 @@ def _make_kernel(connectivity, max_iters: int, batched: bool = False):
             return new, changed, it + 1
 
         J, _, iters = jax.lax.while_loop(cond, body, (J, jnp.bool_(True), jnp.int32(0)))
-        if batched:
-            o_ref[0] = J
-            iters_ref[0, 0, 0] = iters
-        else:
-            o_ref[...] = J
-            iters_ref[0, 0] = iters
+        o_ref[...] = J.reshape(o_ref.shape)
+        # Full-block store: Mosaic cannot store a scalar to VMEM.
+        iters_ref[...] = jnp.full(iters_ref.shape, iters)
 
     return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("connectivity", "max_iters", "interpret"))
 def morph_tile_solve(J, I, valid, *, connectivity=8, max_iters: int = 1024,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """Drain one (T+2, ...) halo block to local stability.
 
     Returns (J_out, iters).  Halo faces are read as propagation sources
@@ -125,7 +124,7 @@ def morph_tile_solve(J, I, valid, *, connectivity=8, max_iters: int = 1024,
         out_shape=out_shape,
         in_specs=[_full(J.shape), _full(I.shape), _full(valid.shape)],
         out_specs=(_full(J.shape), _full((1, 1))),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(J, I, valid)
     return J_out, iters[0, 0]
 
@@ -217,14 +216,9 @@ def _make_queued_kernel(connectivity, max_iters: int, capacity: int,
             dense_round, queued_round, J,
             max_iters=max_iters, capacity=capacity,
             initial_queue=initial_queue)
-        if batched:
-            o_ref[0] = J
-            iters_ref[0, 0, 0] = iters
-            spills_ref[0, 0, 0] = spills
-        else:
-            o_ref[...] = J
-            iters_ref[0, 0] = iters
-            spills_ref[0, 0] = spills
+        o_ref[...] = J.reshape(o_ref.shape)
+        iters_ref[...] = jnp.full(iters_ref.shape, iters)
+        spills_ref[...] = jnp.full(spills_ref.shape, spills)
 
     return kernel
 
@@ -239,7 +233,7 @@ def _clip_capacity(queue_capacity: int, n: int, n_offsets: int) -> int:
                                              "queue_capacity", "interpret"))
 def morph_tile_solve_queued(J, I, valid, seed=None, *, connectivity=8,
                             max_iters: int = 1024, queue_capacity: int = 64,
-                            interpret: bool = True):
+                            interpret: Optional[bool] = None):
     """Queued drain of one (T+2, ...) halo block (DESIGN.md §2.5).
 
     Returns (J_out, iters, spills): bit-identical J_out and iters to
@@ -276,7 +270,7 @@ def morph_tile_solve_queued(J, I, valid, seed=None, *, connectivity=8,
         out_shape=out_shape,
         in_specs=in_specs,
         out_specs=(_full(J.shape), scalar, scalar),
-        interpret=interpret,
+        interpret=queued_interpret(interpret),
     )(*args)
     return J_out, iters[0, 0], spills[0, 0]
 
@@ -287,7 +281,7 @@ def morph_tile_solve_queued_batched(J, I, valid, seed=None, *,
                                     connectivity=8,
                                     max_iters: int = 1024,
                                     queue_capacity: int = 64,
-                                    interpret: bool = True):
+                                    interpret: Optional[bool] = None):
     """Queued drain of a (K, T+2, ...) batch; each grid step owns one block
     and one local queue.  Returns (J_out, iters, spills), both (K,).
 
@@ -321,14 +315,14 @@ def morph_tile_solve_queued_batched(J, I, valid, seed=None, *,
         out_shape=out_shape,
         in_specs=in_specs,
         out_specs=(blk, scalar, scalar),
-        interpret=interpret,
+        interpret=queued_interpret(interpret),
     )(*args)
     return J_out, iters[:, 0, 0], spills[:, 0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("connectivity", "max_iters", "interpret"))
 def morph_tile_solve_batched(J, I, valid, *, connectivity=8,
-                             max_iters: int = 1024, interpret: bool = True):
+                             max_iters: int = 1024, interpret: Optional[bool] = None):
     """Drain a (K, T+2, ...) batch of halo blocks concurrently.
 
     One ``pallas_call`` with ``grid=(K,)``: each grid step owns one block and
@@ -350,6 +344,6 @@ def morph_tile_solve_batched(J, I, valid, *, connectivity=8,
         out_shape=out_shape,
         in_specs=[blk, blk, blk],
         out_specs=(blk, pl.BlockSpec((1, 1, 1), lambda k: (k, 0, 0))),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(J, I, valid)
     return J_out, iters[:, 0, 0]

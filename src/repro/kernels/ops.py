@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -70,7 +71,7 @@ def _up(x):
     return x, None
 
 
-def morph_tile_pallas(J, I, valid, connectivity: int = 8, interpret: bool = True,
+def morph_tile_pallas(J, I, valid, connectivity: int = 8, interpret: Optional[bool] = None,
                       max_iters: int = DEFAULT_MAX_ITERS):
     Ju, orig = _up(J)
     Iu, _ = _up(I)
@@ -79,7 +80,7 @@ def morph_tile_pallas(J, I, valid, connectivity: int = 8, interpret: bool = True
     return (out.astype(orig) if orig is not None else out), iters
 
 
-def tile_solver_morph(connectivity: int = 8, interpret: bool = True,
+def tile_solver_morph(connectivity: int = 8, interpret: Optional[bool] = None,
                       max_iters: int = DEFAULT_MAX_ITERS):
     """Adapter: tiled-engine `tile_solver` backed by the Pallas kernel."""
     def solver(block):
@@ -92,7 +93,7 @@ def tile_solver_morph(connectivity: int = 8, interpret: bool = True,
 
 
 def morph_tile_pallas_batched(J, I, valid, connectivity: int = 8,
-                              interpret: bool = True,
+                              interpret: Optional[bool] = None,
                               max_iters: int = DEFAULT_MAX_ITERS):
     """(K, T+2, T+2) batch drain; returns (J_out, iters[K])."""
     Ju, orig = _up(J)
@@ -104,7 +105,7 @@ def morph_tile_pallas_batched(J, I, valid, connectivity: int = 8,
     return (out.astype(orig) if orig is not None else out), iters
 
 
-def tile_solver_morph_batched(connectivity: int = 8, interpret: bool = True,
+def tile_solver_morph_batched(connectivity: int = 8, interpret: Optional[bool] = None,
                               max_iters: int = DEFAULT_MAX_ITERS):
     """Adapter: tiled-engine `batched_tile_solver` backed by the grid kernel."""
     def solver(blocks):
@@ -127,7 +128,7 @@ def _label_as_morph(blocks):
     return blocks["lab"], I
 
 
-def tile_solver_label(connectivity: int = 8, interpret: bool = True,
+def tile_solver_label(connectivity: int = 8, interpret: Optional[bool] = None,
                       max_iters: int = DEFAULT_MAX_ITERS):
     """Adapter: the *morph* Pallas kernel, parametrized into the label op's
     masked-max update (DESIGN.md §2.4 — new ops reuse kernels through the
@@ -144,7 +145,7 @@ def tile_solver_label(connectivity: int = 8, interpret: bool = True,
     return solver
 
 
-def tile_solver_label_batched(connectivity: int = 8, interpret: bool = True,
+def tile_solver_label_batched(connectivity: int = 8, interpret: Optional[bool] = None,
                               max_iters: int = DEFAULT_MAX_ITERS):
     """Batched (K, T+2, T+2) variant over the morph grid-over-batch kernel."""
     def solver(blocks):
@@ -166,7 +167,7 @@ def _edt_coords(state_block, ndim: int, stack_axis: int = 0):
                      axis=stack_axis)
 
 
-def edt_tile_pallas(state_block, connectivity=8, interpret: bool = True,
+def edt_tile_pallas(state_block, connectivity=8, interpret: Optional[bool] = None,
                     max_iters: int = DEFAULT_MAX_ITERS):
     vr = state_block["vr"]  # (ndim, *spatial)
     o, iters = edt_tile_solve_nd(
@@ -177,7 +178,7 @@ def edt_tile_pallas(state_block, connectivity=8, interpret: bool = True,
     return out, iters
 
 
-def tile_solver_edt(connectivity: int = 8, interpret: bool = True,
+def tile_solver_edt(connectivity: int = 8, interpret: Optional[bool] = None,
                     max_iters: int = DEFAULT_MAX_ITERS):
     def solver(block):
         out, iters = edt_tile_pallas(block, connectivity, interpret, max_iters)
@@ -186,7 +187,7 @@ def tile_solver_edt(connectivity: int = 8, interpret: bool = True,
 
 
 def edt_tile_pallas_batched(state_blocks, connectivity=8,
-                            interpret: bool = True,
+                            interpret: Optional[bool] = None,
                             max_iters: int = DEFAULT_MAX_ITERS):
     """Batched EDT drain over leaves with a leading (K,) batch dim."""
     vr = state_blocks["vr"]  # (K, ndim, *spatial)
@@ -199,7 +200,7 @@ def edt_tile_pallas_batched(state_blocks, connectivity=8,
     return out, iters
 
 
-def tile_solver_edt_batched(connectivity: int = 8, interpret: bool = True,
+def tile_solver_edt_batched(connectivity: int = 8, interpret: Optional[bool] = None,
                             max_iters: int = DEFAULT_MAX_ITERS):
     def solver(blocks):
         out, iters = edt_tile_pallas_batched(blocks, connectivity, interpret,
@@ -223,7 +224,7 @@ def tile_solver_edt_batched(connectivity: int = 8, interpret: bool = True,
 # ---------------------------------------------------------------------------
 
 def morph_tile_pallas_queued(J, I, valid, connectivity: int = 8,
-                             interpret: bool = True,
+                             interpret: Optional[bool] = None,
                              max_iters: int = DEFAULT_MAX_ITERS,
                              queue_capacity: int | None = None,
                              queue=None):
@@ -237,7 +238,7 @@ def morph_tile_pallas_queued(J, I, valid, connectivity: int = 8,
     return (out.astype(orig) if orig is not None else out), iters, spills
 
 
-def tile_solver_morph_queued(connectivity: int = 8, interpret: bool = True,
+def tile_solver_morph_queued(connectivity: int = 8, interpret: Optional[bool] = None,
                              max_iters: int = DEFAULT_MAX_ITERS,
                              queue_capacity: int | None = None):
     """`tile_solver` backed by the queued morph kernel."""
@@ -252,7 +253,7 @@ def tile_solver_morph_queued(connectivity: int = 8, interpret: bool = True,
 
 
 def tile_solver_morph_queued_batched(connectivity: int = 8,
-                                     interpret: bool = True,
+                                     interpret: Optional[bool] = None,
                                      max_iters: int = DEFAULT_MAX_ITERS,
                                      queue_capacity: int | None = None):
     """`batched_tile_solver` over the queued grid-over-batch morph kernel."""
@@ -270,7 +271,7 @@ def tile_solver_morph_queued_batched(connectivity: int = 8,
     return solver
 
 
-def tile_solver_label_queued(connectivity: int = 8, interpret: bool = True,
+def tile_solver_label_queued(connectivity: int = 8, interpret: Optional[bool] = None,
                              max_iters: int = DEFAULT_MAX_ITERS,
                              queue_capacity: int | None = None):
     """Queued morph kernel parametrized into the label masked-max update."""
@@ -288,7 +289,7 @@ def tile_solver_label_queued(connectivity: int = 8, interpret: bool = True,
 
 
 def tile_solver_label_queued_batched(connectivity: int = 8,
-                                     interpret: bool = True,
+                                     interpret: Optional[bool] = None,
                                      max_iters: int = DEFAULT_MAX_ITERS,
                                      queue_capacity: int | None = None):
     def solver(blocks, queue=None):
@@ -304,7 +305,7 @@ def tile_solver_label_queued_batched(connectivity: int = 8,
     return solver
 
 
-def tile_solver_edt_queued(connectivity=8, interpret: bool = True,
+def tile_solver_edt_queued(connectivity=8, interpret: Optional[bool] = None,
                            max_iters: int = DEFAULT_MAX_ITERS,
                            queue_capacity: int | None = None):
     def solver(block, queue=None):
@@ -322,7 +323,7 @@ def tile_solver_edt_queued(connectivity=8, interpret: bool = True,
 
 
 def tile_solver_edt_queued_batched(connectivity=8,
-                                   interpret: bool = True,
+                                   interpret: Optional[bool] = None,
                                    max_iters: int = DEFAULT_MAX_ITERS,
                                    queue_capacity: int | None = None):
     def solver(blocks, queue=None):
@@ -340,7 +341,7 @@ def tile_solver_edt_queued_batched(connectivity=8,
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def raster_pass_kernel(J, I, interpret: bool = True):
+def raster_pass_kernel(J, I, interpret: Optional[bool] = None):
     """Full raster half-pass (left->right then top->down) via the kernel.
 
     Left->right is the same recurrence on the transpose.
@@ -353,7 +354,7 @@ def raster_pass_kernel(J, I, interpret: bool = True):
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def antiraster_pass_kernel(J, I, interpret: bool = True):
+def antiraster_pass_kernel(J, I, interpret: Optional[bool] = None):
     Ju, orig = _up(J)
     Iu, _ = _up(I)
     Jt = raster_down(Ju[:, ::-1].T, Iu[:, ::-1].T, interpret=interpret).T[:, ::-1]

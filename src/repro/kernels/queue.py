@@ -40,12 +40,27 @@ compaction is one cumsum + one scatter, both vector-unit friendly.
 
 from __future__ import annotations
 
-from typing import Callable, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.pattern import shiftnd
+from repro.kernels import resolve_interpret
+
+QUEUE_LOWERING_GAP = (
+    "the queued tile kernels (kernel_queue=True, DESIGN.md §2.5) do not "
+    "compile for a TPU yet: Mosaic has no lowering for cumsum, which "
+    "compact_mask/compact_flags use (kernels/queue.py); drain with the "
+    "dense kernels (kernel_queue=False) when Pallas runs compiled")
+
+
+def queued_interpret(interpret: Optional[bool]) -> bool:
+    """Resolve ``interpret`` for a queued kernel; raise
+    ``NotImplementedError`` naming the lowering gap when it would compile."""
+    if not resolve_interpret(interpret):
+        raise NotImplementedError(QUEUE_LOWERING_GAP)
+    return True
 
 
 def _iota1d(n: int) -> jnp.ndarray:

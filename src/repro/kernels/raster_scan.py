@@ -14,10 +14,13 @@ are independent for this direction).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _kernel(j_ref, i_ref, o_ref):
@@ -36,7 +39,7 @@ def _kernel(j_ref, i_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
-def raster_down(J, I, *, block_w: int = 512, interpret: bool = True):
+def raster_down(J, I, *, block_w: int = 512, interpret: Optional[bool] = None):
     """Top-to-bottom FH pass: v[r] = min(I[r], max(J[r], v[r-1]))."""
     H, W = J.shape
     bw = min(block_w, W)
@@ -49,5 +52,5 @@ def raster_down(J, I, *, block_w: int = 512, interpret: bool = True):
                   pl.BlockSpec((H, bw), lambda c: (0, c))],
         out_specs=pl.BlockSpec((H, bw), lambda c: (0, c)),
         grid=grid,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(J, I)

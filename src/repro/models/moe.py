@@ -175,11 +175,11 @@ def moe_apply_auto(p, x, cfg: MoEConfig, mlp_kind: str, *, dropless=False):
         aux = jax.lax.pmean(jax.lax.pmean(aux, dp_axes), tp_axis)
         return y, aux
 
-    from repro.core.distributed import shard_map_compat
-    fn = shard_map_compat(
-        island, mesh,
-        (param_specs, P_(dp_axes, *([None] * (x.ndim - 1)))),
-        (P_(dp_axes, *([None] * (x.ndim - 1))), P_()))
+    fn = jax.shard_map(
+        island, mesh=mesh,
+        in_specs=(param_specs, P_(dp_axes, *([None] * (x.ndim - 1)))),
+        out_specs=(P_(dp_axes, *([None] * (x.ndim - 1))), P_()),
+        check_vma=False)
     return fn(p, x)
 
 

@@ -89,7 +89,8 @@ class IwppService:
         :meth:`start`.
     """
 
-    def __init__(self, *, engine: str = "auto", interpret: bool = True,
+    def __init__(self, *, engine: str = "auto",
+                 interpret: Optional[bool] = None,
                  autotune: bool = False, cost_model=None,
                  max_batch: int = 8, batch_window_s: float = 0.002,
                  max_queue_depth: int = 64,

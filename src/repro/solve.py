@@ -63,6 +63,8 @@ from repro.core.scheduler import ChunkPolicy, DeviceWorker, TileScheduler
 from repro.core.tiles import (active_tiles_from_frontier, default_batched_solver,
                               default_tile_solver, initial_active_tiles,
                               run_tiled)
+from repro.kernels import resolve_interpret
+from repro.kernels.queue import QUEUE_LOWERING_GAP
 # Importing repro.ops registers the built-in op catalog (morph, edt,
 # fill_holes, label) before any dispatch can happen.
 from repro.ops import (amend_op_class, get_op, list_ops, on_spec_change,
@@ -140,6 +142,14 @@ class SolveStats:
     # Requests coalesced into the one solve that produced this record
     # (solve_batch's vmapped dense path); None for solo solves.
     batch_size: Optional[int] = None
+    # The resolved Pallas mode the run used (repro.kernels.resolve_interpret:
+    # compiled exactly on a TPU backend unless the caller forced it).
+    interpret: Optional[bool] = None
+    # Host-scheduler engines (scheduler, hybrid): tiles the hybrid device
+    # streams drained, and the repr of every exception a worker died on
+    # (injected test failures excluded; each one was also warned about).
+    device_tiles: int = 0
+    worker_errors: Tuple[str, ...] = ()
 
 
 # ---------------------------------------------------------------------------
@@ -334,8 +344,8 @@ class CostModel:
     # observed it.
     recompile_cost = 2_000_000.0
 
-    def __init__(self, interpret: bool = True):
-        self.interpret = interpret
+    def __init__(self, interpret: Optional[bool] = None):
+        self.interpret = resolve_interpret(interpret)
         # engine name -> EWMA of observed recompiles per outer round,
         # fed by `calibrate`.  Empty = trust the engines' no-leak contract.
         self._recompile_rate: Dict[str, float] = {}
@@ -537,8 +547,9 @@ class CostModel:
             db = min(cap, _default_drain_batch(t))
             out.append(EngineConfig("tiled", t, cap, db))
             out.append(EngineConfig("tiled-pallas", t, cap, db))
-            out.append(EngineConfig("tiled-pallas", t, cap, db,
-                                    kernel_queue=True))
+            if self.interpret:  # queued kernels do not compile yet
+                out.append(EngineConfig("tiled-pallas", t, cap, db,
+                                        kernel_queue=True))
             out.append(EngineConfig("scheduler", t, cap))
             out.append(EngineConfig("hybrid", t, cap, db))
             if stats.n_devices > 1:
@@ -588,7 +599,7 @@ class MeasuredCostModel(CostModel):
 
     kind = "measured"
 
-    def __init__(self, profile, interpret: bool = True):
+    def __init__(self, profile, interpret: Optional[bool] = None):
         super().__init__(interpret)
         self.profile = profile
 
@@ -750,7 +761,7 @@ class MeasuredCostModel(CostModel):
         return self._bridge(stats, cfg)
 
 
-def default_cost_model(interpret: bool = True) -> CostModel:
+def default_cost_model(interpret: Optional[bool] = None) -> CostModel:
     """The model ``engine="auto"`` uses when the caller passed none: the
     :class:`MeasuredCostModel` over the installed calibration profile when
     one exists for this (device kind, code version), else the analytic
@@ -1014,7 +1025,7 @@ def _tiled_cfg_defaults(cfg: EngineConfig) -> Tuple[int, int, int]:
     return tile, cap, drain_batch
 
 
-def _run_tiled_engine(op, state, cfg, max_rounds, interpret=True, **_):
+def _run_tiled_engine(op, state, cfg, max_rounds, interpret=None, **_):
     solver = batched_solver = None
     tile, cap, drain_batch = _tiled_cfg_defaults(cfg)
     kq = bool(cfg.kernel_queue)
@@ -1194,7 +1205,7 @@ def _run_scheduler_engine(op, state, cfg, max_rounds, n_workers=4, **_):
                            tiles_processed=st.tiles_processed,
                            requeues=st.requeues_from_failures,
                            tiles_requeued=st.tiles_requeued,
-                           tile=tile)
+                           tile=tile, worker_errors=tuple(st.worker_errors))
 
 
 def _bp_residual_for(op):
@@ -1223,7 +1234,7 @@ def _bp_residual_for(op):
 _HYBRID_FAIL_INJECT: Optional[Tuple[Any, int]] = None
 
 
-def _run_hybrid_engine(op, state, cfg, max_rounds, interpret=True,
+def _run_hybrid_engine(op, state, cfg, max_rounds, interpret=None,
                        n_workers=4, n_device_workers=1,
                        hybrid_pallas=False, cost_model=None, **_):
     """The cooperative CPU+device engine (paper §4, DESIGN.md §2.3).
@@ -1266,7 +1277,8 @@ def _run_hybrid_engine(op, state, cfg, max_rounds, interpret=True,
     residual = _bp_residual_for(op)
     fail = _HYBRID_FAIL_INJECT
 
-    tiles_processed = requeues = tiles_requeued = 0
+    tiles_processed = requeues = tiles_requeued = device_tiles = 0
+    worker_errors: List[str] = []
     bp_rounds = 0
     incomplete = True
     while True:
@@ -1280,6 +1292,8 @@ def _run_hybrid_engine(op, state, cfg, max_rounds, interpret=True,
         tiles_processed += st.tiles_processed
         requeues += st.requeues_from_failures
         tiles_requeued += st.tiles_requeued
+        device_tiles += st.device_tiles
+        worker_errors += st.worker_errors
         bp_rounds += 1
         if not st.incomplete:
             # A completed pass certifies the fixed point by construction:
@@ -1318,7 +1332,8 @@ def _run_hybrid_engine(op, state, cfg, max_rounds, interpret=True,
                            tiles_processed=tiles_processed,
                            requeues=requeues, tiles_requeued=tiles_requeued,
                            tile=tile, drain_batch=drain_batch,
-                           incomplete=incomplete)
+                           incomplete=incomplete, device_tiles=device_tiles,
+                           worker_errors=tuple(worker_errors))
 
 
 _ENGINE_RUNNERS = {
@@ -1337,6 +1352,7 @@ def _run_engine(op, state, cfg: EngineConfig, **kw):
     # `recompiles` is the compile-cache miss delta across the run: 0 on a
     # warm re-solve, and — the DESIGN.md §2.6 contract — *independent of
     # the round count* even on a cold one (tests/test_runstate.py).
+    kw["interpret"] = resolve_interpret(kw.get("interpret"))
     t0 = time.monotonic()
     with compile_cache.MissSnapshot() as snap:
         out, st = _ENGINE_RUNNERS[cfg.engine](op, state, cfg, **kw)
@@ -1345,7 +1361,8 @@ def _run_engine(op, state, cfg: EngineConfig, **kw):
     # future and wall_time_s would under-report the actual solve.
     jax.block_until_ready(out)
     return out, dataclasses.replace(st, recompiles=snap.count,
-                                    wall_time_s=time.monotonic() - t0)
+                                    wall_time_s=time.monotonic() - t0,
+                                    interpret=kw["interpret"])
 
 
 # ---------------------------------------------------------------------------
@@ -1365,7 +1382,7 @@ def solve(op, state, *, engine: str = "auto",
           autotune: bool = False,
           autotune_top_k: int = 3,
           autotune_repeats: int = 2,
-          interpret: bool = True,
+          interpret: Optional[bool] = None,
           n_workers: int = 4,
           n_device_workers: int = 1,
           hybrid_pallas: bool = False) -> Tuple[Any, SolveStats]:
@@ -1423,7 +1440,12 @@ def solve(op, state, *, engine: str = "auto",
         ``autotune_top_k`` candidates on this input (``autotune_repeats``
         timed runs each after a warm-up) and cache the winner keyed by
         :func:`autotune_signature`.
-    interpret : run Pallas kernels in interpret mode (required off-TPU).
+    interpret : run Pallas kernels in interpret mode.  ``None`` (default)
+        decides from the backend (:func:`repro.kernels.default_interpret`):
+        compiled on a TPU, interpreted elsewhere.  ``SolveStats.interpret``
+        reports the resolved value.  ``kernel_queue=True`` raises
+        ``NotImplementedError`` when the kernels would run compiled — the
+        queued kernels do not lower for a TPU yet.
     n_workers : host threads for the ``"scheduler"`` and ``"hybrid"``
         engines (``"hybrid"`` accepts 0 for a device-only pool).
     n_device_workers : batched device drain streams sharing the
@@ -1445,6 +1467,9 @@ def solve(op, state, *, engine: str = "auto",
         raise ValueError(
             "connectivity= applies to by-name solve() calls only; construct "
             "the op instance with the desired connectivity instead")
+    interpret = resolve_interpret(interpret)
+    if kernel_queue and not interpret:
+        raise NotImplementedError(QUEUE_LOWERING_GAP)
     run_kw = dict(max_rounds=max_rounds, devices=devices,
                   interpret=interpret, n_workers=n_workers,
                   n_device_workers=n_device_workers,
@@ -1553,7 +1578,7 @@ def solve_batch(op, states: Sequence[Any], *,
                 cost_model: Optional[CostModel] = None,
                 autotune: bool = False,
                 max_rounds: int = 1_000_000,
-                interpret: bool = True,
+                interpret: Optional[bool] = None,
                 **engine_kw) -> List[Tuple[Any, SolveStats]]:
     """Solve ``len(states)`` independent same-shaped inputs as one batch.
 
@@ -1597,6 +1622,7 @@ def solve_batch(op, states: Sequence[Any], *,
     states = list(states)
     if not states:
         return []
+    interpret = resolve_interpret(interpret)
     sig0 = _tree_signature(states[0])
     for i, s in enumerate(states[1:], start=1):
         if _tree_signature(s) != sig0:
@@ -1655,7 +1681,8 @@ def solve_batch(op, states: Sequence[Any], *,
                 sources_processed=(int(rst.sources_hi[i]) << 32)
                 | int(rst.sources_lo[i]),
                 recompiles=snap.count, cost_model=decided_by,
-                wall_time_s=wall, batch_size=len(states))
+                wall_time_s=wall, batch_size=len(states),
+                interpret=interpret)
             results.append(
                 (jax.tree_util.tree_map(lambda x: x[i], out), st_i))
         return results

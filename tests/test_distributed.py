@@ -276,10 +276,10 @@ def test_compressed_dp_psum_close_to_exact():
         def f(gl, efl):
             out, ef2 = compressed_psum(gl, efl, "data")
             return out, ef2
-        from repro.core.distributed import shard_map_compat
-        fn = jax.jit(shard_map_compat(f, mesh,
-            (jax.sharding.PartitionSpec("data"),) * 2,
-            (jax.sharding.PartitionSpec("data"),) * 2))
+        fn = jax.jit(jax.shard_map(f, mesh=mesh,
+            in_specs=(jax.sharding.PartitionSpec("data"),) * 2,
+            out_specs=(jax.sharding.PartitionSpec("data"),) * 2,
+            check_vma=False))
         out, ef2 = fn(g, ef)
         exact = jnp.mean(g, axis=0, keepdims=True)
         rel = float(jnp.max(jnp.abs(out[0] - exact[0]))) / float(jnp.max(jnp.abs(exact)))

@@ -68,6 +68,9 @@ def test_hybrid_pool_shapes_match_morph_ref(morph_case, pool):
     np.testing.assert_array_equal(np.asarray(out["J"]), ref)
     assert st.engine == "hybrid" and not st.incomplete
     assert st.tiles_processed > 0 and st.rounds >= 1
+    assert st.worker_errors == ()
+    if pool["n_workers"] == 0:
+        assert st.device_tiles == st.tiles_processed
 
 
 def test_hybrid_pallas_device_drain_matches_ref(morph_case, edt_case):
@@ -128,6 +131,39 @@ def test_device_worker_death_hosts_finish_distance_exact(edt_case, fail_inject):
     np.testing.assert_array_equal(np.asarray(distance_map(out)), ref_M)
     assert st.requeues >= 1
     assert not st.incomplete
+
+
+def test_real_device_error_recorded_and_hosts_finish(morph_case, monkeypatch):
+    """A device drain that raises a real (not injected) error — e.g. a
+    kernel that fails to compile — must not vanish into the fault-tolerance
+    path: SchedulerStats records its repr, a RuntimeWarning names it, and
+    the host threads still finish the queue bit-exact."""
+    op, state, ref = morph_case
+
+    def broken_batch_fn(blocks):
+        raise ValueError("device drain failed to lower")
+
+    monkeypatch.setattr(solve_mod, "_batched_drain_for",
+                        lambda *a, **k: broken_batch_fn)
+    with pytest.warns(RuntimeWarning, match="device drain failed to lower"):
+        out, st = solve(op, state, engine="hybrid", tile=16, drain_batch=2,
+                        n_workers=2, n_device_workers=1)
+    np.testing.assert_array_equal(np.asarray(out["J"]), ref)
+    assert not st.incomplete and st.device_tiles == 0
+    assert st.worker_errors and all(
+        "ValueError('device drain failed to lower')" in e
+        for e in st.worker_errors)
+
+
+def test_injected_failures_are_not_recorded_as_errors(morph_case, fail_inject):
+    op, state, ref = morph_case
+    fail_inject((2, 0))
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        out, st = solve(op, state, engine="hybrid", tile=16, drain_batch=2,
+                        n_workers=2, n_device_workers=1)
+    np.testing.assert_array_equal(np.asarray(out["J"]), ref)
+    assert st.requeues >= 1 and st.worker_errors == ()
 
 
 def test_hybrid_incomplete_surfaced(morph_case, fail_inject, monkeypatch):
