@@ -22,6 +22,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.core.pattern import PropagationOp, restore_invalid
 
 
@@ -45,8 +46,10 @@ class RunStats(NamedTuple):
 
     @property
     def sources_processed(self) -> int:
-        """Exact total frontier pixels acted on (host-side int)."""
-        return (int(self.sources_hi) << 32) | int(self.sources_lo)
+        """Exact total frontier pixels acted on (host-side int: two
+        counted device -> host reads)."""
+        return ((spans.host_int(self.sources_hi) << 32)
+                | spans.host_int(self.sources_lo))
 
 
 @partial(jax.jit, static_argnums=(0, 2, 3))

@@ -37,6 +37,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, Optional, Tuple, Union
 
+from repro.core import spans
+
 __all__ = [
     "OpSpec", "register_op", "get_op", "list_ops", "spec_for",
     "amend_op_class", "default_scheduler_merge", "on_spec_change", "run_op",
@@ -268,6 +270,7 @@ def amend_op_class(op_cls: type, **fields) -> OpSpec:
     return spec
 
 
+@spans.recorded("iwpp.run_op")
 def run_op(name: str, *inputs, connectivity: Optional[Union[int, str]] = None,
            **solve_kw):
     """Run a registered op end to end: build, solve, extract.
@@ -282,6 +285,9 @@ def run_op(name: str, *inputs, connectivity: Optional[Union[int, str]] = None,
     """
     from repro.solve import solve
     spec = get_op(name)
-    op = spec.make_op(connectivity)
-    out, stats = solve(op, spec.build_state(op, *inputs), **solve_kw)
-    return spec.extract(op, out), stats
+    with spans.span("iwpp.build_state"):
+        op = spec.make_op(connectivity)
+        state = spec.build_state(op, *inputs)
+    out, stats = solve(op, state, **solve_kw)
+    with spans.span("iwpp.extract"):
+        return spec.extract(op, out), stats

@@ -55,14 +55,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import autotune_disk, calibrate, compile_cache
+from repro.core import autotune_disk, calibrate, compile_cache, spans
+from repro.core import tiles as _tiles
 from repro.core.distributed import run_sharded
 from repro.core.frontier import run_dense
 from repro.core.pattern import PropagationOp, restore_invalid, tree_shape
 from repro.core.scheduler import ChunkPolicy, DeviceWorker, TileScheduler
 from repro.core.tiles import (active_tiles_from_frontier, default_batched_solver,
-                              default_tile_solver, initial_active_tiles,
-                              run_tiled)
+                              default_tile_solver, initial_active_tiles)
 from repro.kernels import resolve_interpret
 from repro.kernels.queue import QUEUE_LOWERING_GAP
 # Importing repro.ops registers the built-in op catalog (morph, edt,
@@ -134,10 +134,8 @@ class SolveStats:
     # "measured" (a calibration profile was installed; DESIGN.md §2.8).
     # None for explicitly-chosen engines — nothing decided anything.
     cost_model: Optional[str] = None
-    # Monotonic-clock wall seconds of the engine run, measured around the
-    # engine adapter with the output forced resident (block_until_ready) —
-    # the one truthful latency source the serving layer (DESIGN.md §2.9)
-    # and the benches report from instead of re-timing around solve().
+    # Seconds of the run's ``iwpp.engine`` span: the engine adapter with the
+    # output forced resident (block_until_ready).
     wall_time_s: float = 0.0
     # Requests coalesced into the one solve that produced this record
     # (solve_batch's vmapped dense path); None for solo solves.
@@ -150,6 +148,11 @@ class SolveStats:
     # (injected test failures excluded; each one was also warned about).
     device_tiles: int = 0
     worker_errors: Tuple[str, ...] = ()
+    # The call's host spans, (name, parent index, start ns, end ns) on
+    # time.monotonic_ns, and its device -> host reads (repro.core.spans;
+    # docs/ENGINES.md "Tracing").  Filled by the outermost entry point.
+    spans: Tuple[Tuple[str, int, int, int], ...] = ()
+    host_syncs: int = 0
 
 
 # ---------------------------------------------------------------------------
@@ -252,10 +255,12 @@ def collect_input_stats(op: PropagationOp, state, n_devices: int = 1,
                         tiles: Sequence[int] = DEFAULT_TILES) -> InputStats:
     spatial = tree_shape(state, op.ndim)
     H, W = spatial[-2:]
-    f0 = op.init_frontier(state)
-    n_sources = int(jnp.sum(f0))
-    active = {t: int(jnp.sum(initial_active_tiles(op, state, t)))
-              for t in tiles}
+    with spans.span("iwpp.select.input_stats"):
+        f0 = op.init_frontier(state)
+        n_sources = spans.host_int(jnp.sum(f0))
+        active = {t: spans.host_int(
+                      jnp.sum(initial_active_tiles(op, state, t)))
+                  for t in tiles}
     spec = spec_for(op)
     return InputStats(H, W, n_sources, active, n_devices,
                       bytes_per_pixel=spec.bytes_per_pixel if spec else 4.0,
@@ -917,9 +922,10 @@ def _mesh_shape(n: int) -> Tuple[int, int]:
 
 
 def _run_dense_engine(op, state, cfg, max_rounds, **_):
-    out, st = run_dense(op, state, cfg.engine, max_rounds)
-    return out, SolveStats(cfg.engine, rounds=int(st.rounds),
-                           sources_processed=int(st.sources_processed))
+    with spans.span("iwpp.engine.wait"):
+        out, st = run_dense(op, state, cfg.engine, max_rounds)
+        return out, SolveStats(cfg.engine, rounds=spans.host_int(st.rounds),
+                               sources_processed=st.sources_processed)
 
 
 # Every per-op compiled artifact in this module lives in the one process
@@ -1026,64 +1032,76 @@ def _tiled_cfg_defaults(cfg: EngineConfig) -> Tuple[int, int, int]:
 
 
 def _run_tiled_engine(op, state, cfg, max_rounds, interpret=None, **_):
-    solver = batched_solver = None
-    tile, cap, drain_batch = _tiled_cfg_defaults(cfg)
-    kq = bool(cfg.kernel_queue)
-    kq_cap = None
-    if cfg.engine == "tiled-pallas":
-        # Thread the engine's prod(T_i+2) geodesic bound into the kernels:
-        # the kernel-default 1024 is *below* the bound for any 2-D tile
-        # >= 32, and a drain cut off there must re-queue, not masquerade as
-        # converged.
-        max_iters = (tile + 2) ** op.ndim
-        if kq:
-            from repro.kernels.ops import default_kernel_queue_capacity
-            kq_cap = (cfg.kernel_queue_capacity
-                      or default_kernel_queue_capacity(
-                          (tile + 2,) * op.ndim))
-        solver = _pallas_solver_for(op, interpret, max_iters=max_iters,
-                                    engine=cfg.engine, kernel_queue=kq,
-                                    kernel_queue_capacity=kq_cap)
-        if drain_batch > 1:
-            batched_solver = _pallas_solver_for(op, interpret, batched=True,
-                                                max_iters=max_iters,
-                                                engine=cfg.engine,
-                                                kernel_queue=kq,
-                                                kernel_queue_capacity=kq_cap)
-    out, st = run_tiled(op, state, tile=tile, queue_capacity=cap,
-                        max_outer_rounds=max_rounds, tile_solver=solver,
-                        drain_batch=drain_batch,
-                        batched_tile_solver=batched_solver)
-    return out, SolveStats(cfg.engine, rounds=int(st.outer_rounds),
-                           tiles_processed=int(st.tiles_processed),
-                           overflow_events=int(st.overflow_events),
-                           tiles_requeued=int(st.tiles_requeued),
-                           tile=tile, queue_capacity=cap,
-                           drain_batch=drain_batch,
-                           kernel_queue=kq, kernel_queue_capacity=kq_cap)
+    # core.tiles.run_tiled's prepare -> drain -> finalize, split so that the
+    # host set-up and the wait for the device loop are spans of their own.
+    with spans.span("iwpp.engine.prepare"):
+        solver = batched_solver = None
+        tile, cap, drain_batch = _tiled_cfg_defaults(cfg)
+        kq = bool(cfg.kernel_queue)
+        kq_cap = None
+        if cfg.engine == "tiled-pallas":
+            # Thread the engine's prod(T_i+2) geodesic bound into the
+            # kernels: the kernel-default 1024 is *below* the bound for any
+            # 2-D tile >= 32, and a drain cut off there must re-queue, not
+            # masquerade as converged.
+            max_iters = (tile + 2) ** op.ndim
+            if kq:
+                from repro.kernels.ops import default_kernel_queue_capacity
+                kq_cap = (cfg.kernel_queue_capacity
+                          or default_kernel_queue_capacity(
+                              (tile + 2,) * op.ndim))
+            solver = _pallas_solver_for(op, interpret, max_iters=max_iters,
+                                        engine=cfg.engine, kernel_queue=kq,
+                                        kernel_queue_capacity=kq_cap)
+            if drain_batch > 1:
+                batched_solver = _pallas_solver_for(
+                    op, interpret, batched=True, max_iters=max_iters,
+                    engine=cfg.engine, kernel_queue=kq,
+                    kernel_queue_capacity=kq_cap)
+        plan, rs = _tiles.prepare(op, state, tile=tile, queue_capacity=cap,
+                                  max_outer_rounds=max_rounds,
+                                  tile_solver=solver, drain_batch=drain_batch,
+                                  batched_tile_solver=batched_solver)
+        drain = _tiles.drain_fn(plan)
+    with spans.span("iwpp.engine.wait"):
+        rs = drain(rs)
+        out = _tiles.finalize(plan, rs, state)
+        st = rs.stats
+        return out, SolveStats(
+            cfg.engine, rounds=spans.host_int(st.outer_rounds),
+            tiles_processed=spans.host_int(st.tiles_processed),
+            overflow_events=spans.host_int(st.overflow_events),
+            tiles_requeued=spans.host_int(st.tiles_requeued),
+            tile=tile, queue_capacity=cap, drain_batch=drain_batch,
+            kernel_queue=kq, kernel_queue_capacity=kq_cap)
 
 
 def _run_shard_map_engine(op, state, cfg, max_rounds, devices=None, **_):
-    devices = list(devices) if devices is not None else jax.devices()
-    nr, nc = _mesh_shape(len(devices))
-    from jax.sharding import Mesh
-    mesh = Mesh(np.asarray(devices).reshape(nr, nc), ("data", "model"))
-    padded, orig = _pad_to_multiple(op, state, (nr, nc))
-    if cfg.engine == "shard_map-tiled":
-        tile, cap, drain_batch = _tiled_cfg_defaults(cfg)
-        out, st = run_sharded(op, padded, mesh, tile=tile,
-                              queue_capacity=cap, drain_batch=drain_batch,
-                              max_bp_rounds=max_rounds)
-        return _crop(out, orig), SolveStats(
-            cfg.engine, rounds=int(st.bp_rounds),
-            tiles_processed=int(st.tiles_processed),
-            overflow_events=int(st.overflow_events),
-            tiles_requeued=int(st.tiles_requeued),
-            tile=tile, queue_capacity=cap, drain_batch=drain_batch,
-            n_devices=len(devices))
-    out, st = run_sharded(op, padded, mesh, max_bp_rounds=max_rounds)
-    return _crop(out, orig), SolveStats("shard_map", rounds=int(st.bp_rounds),
-                                        n_devices=len(devices))
+    with spans.span("iwpp.engine.prepare"):
+        devices = list(devices) if devices is not None else jax.devices()
+        nr, nc = _mesh_shape(len(devices))
+        from jax.sharding import Mesh
+        mesh = Mesh(np.asarray(devices).reshape(nr, nc), ("data", "model"))
+        padded, orig = _pad_to_multiple(op, state, (nr, nc))
+    with spans.span("iwpp.engine.wait"):
+        if cfg.engine == "shard_map-tiled":
+            tile, cap, drain_batch = _tiled_cfg_defaults(cfg)
+            out, st = run_sharded(op, padded, mesh, tile=tile,
+                                  queue_capacity=cap, drain_batch=drain_batch,
+                                  max_bp_rounds=max_rounds)
+            stats = SolveStats(
+                cfg.engine, rounds=spans.host_int(st.bp_rounds),
+                tiles_processed=spans.host_int(st.tiles_processed),
+                overflow_events=spans.host_int(st.overflow_events),
+                tiles_requeued=spans.host_int(st.tiles_requeued),
+                tile=tile, queue_capacity=cap, drain_batch=drain_batch,
+                n_devices=len(devices))
+        else:
+            out, st = run_sharded(op, padded, mesh, max_bp_rounds=max_rounds)
+            stats = SolveStats("shard_map",
+                               rounds=spans.host_int(st.bp_rounds),
+                               n_devices=len(devices))
+    return _crop(out, orig), stats
 
 
 def _scheduler_drain_for(op, tile: int):
@@ -1184,8 +1202,9 @@ def _scheduler_state_for(op, state, tile: int, engine: str):
 
 def _run_scheduler_engine(op, state, cfg, max_rounds, n_workers=4, **_):
     tile = cfg.tile or DEFAULT_TILES[1]
-    (np_state, active, merge_block_fn, mutable, pad_values,
-     orig) = _scheduler_state_for(op, state, tile, "scheduler")
+    with spans.span("iwpp.engine.prepare"):
+        (np_state, active, merge_block_fn, mutable, pad_values,
+         orig) = _scheduler_state_for(op, state, tile, "scheduler")
     sched = TileScheduler(np_state, tile, _host_tile_fn_for(op, tile), active,
                           n_workers=n_workers, mutable=mutable,
                           merge_block_fn=merge_block_fn,
@@ -1255,8 +1274,9 @@ def _run_hybrid_engine(op, state, cfg, max_rounds, interpret=None,
     if n_workers <= 0 and n_device_workers <= 0:
         raise ValueError("hybrid engine needs n_workers >= 1 or "
                          "n_device_workers >= 1")
-    (np_state, active, merge_block_fn, mutable, pad_values,
-     orig) = _scheduler_state_for(op, state, tile, "hybrid")
+    with spans.span("iwpp.engine.prepare"):
+        (np_state, active, merge_block_fn, mutable, pad_values,
+         orig) = _scheduler_state_for(op, state, tile, "hybrid")
     grid = tuple(s // tile
                  for s in np_state[mutable[0]].shape[-op.ndim:])
 
@@ -1353,15 +1373,16 @@ def _run_engine(op, state, cfg: EngineConfig, **kw):
     # warm re-solve, and — the DESIGN.md §2.6 contract — *independent of
     # the round count* even on a cold one (tests/test_runstate.py).
     kw["interpret"] = resolve_interpret(kw.get("interpret"))
-    t0 = time.monotonic()
-    with compile_cache.MissSnapshot() as snap:
-        out, st = _ENGINE_RUNNERS[cfg.engine](op, state, cfg, **kw)
-    # Force the result resident before closing the clock: with async
-    # dispatch the dense engines would otherwise return an unmaterialized
-    # future and wall_time_s would under-report the actual solve.
-    jax.block_until_ready(out)
+    with spans.span("iwpp.engine") as engine_span:
+        with compile_cache.MissSnapshot() as snap:
+            out, st = _ENGINE_RUNNERS[cfg.engine](op, state, cfg, **kw)
+        # Force the result resident before the span closes: with async
+        # dispatch an engine may return an unmaterialized future, and
+        # wall_time_s would under-report the actual solve.
+        with spans.span("iwpp.engine.wait"):
+            jax.block_until_ready(out)
     return out, dataclasses.replace(st, recompiles=snap.count,
-                                    wall_time_s=time.monotonic() - t0,
+                                    wall_time_s=engine_span.seconds,
                                     interpret=kw["interpret"])
 
 
@@ -1369,6 +1390,7 @@ def _run_engine(op, state, cfg: EngineConfig, **kw):
 # Public API.
 # ---------------------------------------------------------------------------
 
+@spans.recorded("iwpp.solve")
 def solve(op, state, *, engine: str = "auto",
           connectivity: Optional[Union[int, str]] = None,
           devices: Optional[Sequence] = None,
@@ -1458,11 +1480,12 @@ def solve(op, state, *, engine: str = "auto",
         raise ValueError(f"engine must be one of {ENGINES}, got {engine!r}")
     if isinstance(op, str):
         spec = get_op(op)
-        op = spec.make_op(connectivity)
-        if not isinstance(state, dict):
-            # Raw input(s), not a state pytree: build through the spec.
-            inputs = state if isinstance(state, tuple) else (state,)
-            state = spec.build_state(op, *inputs)
+        with spans.span("iwpp.build_state"):
+            op = spec.make_op(connectivity)
+            if not isinstance(state, dict):
+                # Raw input(s), not a state pytree: build through the spec.
+                inputs = state if isinstance(state, tuple) else (state,)
+                state = spec.build_state(op, *inputs)
     elif connectivity is not None:
         raise ValueError(
             "connectivity= applies to by-name solve() calls only; construct "
@@ -1504,45 +1527,48 @@ def _solve_auto(op, state, tile, tiles, n_devices, queue_capacity,
                 autotune_repeats, run_kw) -> Tuple[Any, SolveStats]:
     """The ``engine="auto"`` path: rank candidates, run the winner, report
     which model decided through ``SolveStats.cost_model``."""
-    stats_in = collect_input_stats(op, state, n_devices, tiles)
-    model = (cost_model if cost_model is not None
-             else default_cost_model(interpret=interpret))
+    with spans.span("iwpp.select"):
+        stats_in = collect_input_stats(op, state, n_devices, tiles)
+        model = (cost_model if cost_model is not None
+                 else default_cost_model(interpret=interpret))
+        with spans.span("iwpp.select.rank"):
+            cands = model.candidates(stats_in, tiles)
+            if queue_capacity is not None:
+                cands = [dataclasses.replace(c, queue_capacity=queue_capacity)
+                         if c.queue_capacity is not None else c
+                         for c in cands]
+            if drain_batch is not None:
+                cands = [dataclasses.replace(c, drain_batch=drain_batch)
+                         if c.engine in ("tiled", "tiled-pallas",
+                                         "shard_map-tiled", "hybrid")
+                         else c for c in cands]
+            if kernel_queue is not None:
+                # True/False restricts the tiled-pallas candidates to that
+                # kernel variant; None (the default) lets dense and queued
+                # compete.
+                cands = [c for c in cands
+                         if c.engine != "tiled-pallas"
+                         or c.kernel_queue == bool(kernel_queue)]
+            if kernel_queue_capacity is not None:
+                cands = [dataclasses.replace(
+                    c, kernel_queue_capacity=kernel_queue_capacity)
+                    if c.engine == "tiled-pallas" and c.kernel_queue
+                    else c for c in cands]
+            if autotune:
+                cfg = _autotune(op, state, stats_in, model, cands,
+                                (tile, queue_capacity, drain_batch,
+                                 kernel_queue, kernel_queue_capacity),
+                                autotune_top_k, autotune_repeats, **run_kw)
+            else:
+                cost, cfg = model.rank(stats_in, cands)[0]
 
-    cands = model.candidates(stats_in, tiles)
-    if queue_capacity is not None:
-        cands = [dataclasses.replace(c, queue_capacity=queue_capacity)
-                 if c.queue_capacity is not None else c for c in cands]
-    if drain_batch is not None:
-        cands = [dataclasses.replace(c, drain_batch=drain_batch)
-                 if c.engine in ("tiled", "tiled-pallas", "shard_map-tiled",
-                                 "hybrid")
-                 else c for c in cands]
-    if kernel_queue is not None:
-        # True/False restricts the tiled-pallas candidates to that kernel
-        # variant; None (the default) lets dense and queued compete.
-        cands = [c for c in cands
-                 if c.engine != "tiled-pallas"
-                 or c.kernel_queue == bool(kernel_queue)]
-    if kernel_queue_capacity is not None:
-        cands = [dataclasses.replace(c,
-                                     kernel_queue_capacity=kernel_queue_capacity)
-                 if c.engine == "tiled-pallas" and c.kernel_queue
-                 else c for c in cands]
-
-    if autotune:
-        cfg = _autotune(op, state, stats_in, model, cands,
-                        (tile, queue_capacity, drain_batch, kernel_queue,
-                         kernel_queue_capacity),
-                        autotune_top_k, autotune_repeats, **run_kw)
-        out, st = _run_engine(op, state, cfg, **run_kw)
+    out, st = _run_engine(op, state, cfg, **run_kw)
+    with spans.span("iwpp.calibrate"):
         model.calibrate(st)
+    if autotune:
         return out, dataclasses.replace(
             st, autotuned=True, predicted_cost=model.cost(stats_in, cfg),
             n_devices=max(st.n_devices, 1), cost_model=model.kind)
-
-    cost, cfg = model.rank(stats_in, cands)[0]
-    out, st = _run_engine(op, state, cfg, **run_kw)
-    model.calibrate(st)
     return out, dataclasses.replace(st, predicted_cost=cost,
                                     cost_model=model.kind)
 
@@ -1572,6 +1598,7 @@ def _tree_signature(state):
                         for k, v in state.items()))
 
 
+@spans.recorded("iwpp.solve_batch")
 def solve_batch(op, states: Sequence[Any], *,
                 engine: str = "auto",
                 connectivity: Optional[Union[int, str]] = None,
@@ -1639,20 +1666,22 @@ def solve_batch(op, states: Sequence[Any], *,
         return [(out, st)]
 
     if engine == "auto":
-        stats_in = collect_input_stats(op, states[0])
-        model = (cost_model if cost_model is not None
-                 else default_cost_model(interpret=interpret))
-        cands = model.candidates(stats_in)
-        with calibrate.solve_guard():
-            if autotune:
-                cfg = _autotune(op, states[0], stats_in, model, cands,
-                                ("batch",), top_k=3, repeats=2,
-                                max_rounds=max_rounds, interpret=interpret,
-                                devices=None, n_workers=4,
-                                n_device_workers=1, hybrid_pallas=False,
-                                cost_model=cost_model)
-            else:
-                cfg = model.rank(stats_in, cands)[0][1]
+        with spans.span("iwpp.select"):
+            stats_in = collect_input_stats(op, states[0])
+            model = (cost_model if cost_model is not None
+                     else default_cost_model(interpret=interpret))
+            with spans.span("iwpp.select.rank"), calibrate.solve_guard():
+                cands = model.candidates(stats_in)
+                if autotune:
+                    cfg = _autotune(op, states[0], stats_in, model, cands,
+                                    ("batch",), top_k=3, repeats=2,
+                                    max_rounds=max_rounds,
+                                    interpret=interpret, devices=None,
+                                    n_workers=4, n_device_workers=1,
+                                    hybrid_pallas=False,
+                                    cost_model=cost_model)
+                else:
+                    cfg = model.rank(stats_in, cands)[0][1]
         chosen, decided_by = cfg, model.kind
     else:
         if engine not in ENGINES:
@@ -1666,22 +1695,23 @@ def solve_batch(op, states: Sequence[Any], *,
         decided_by = None
 
     if chosen.engine in BATCHABLE_ENGINES:
-        t0 = time.monotonic()
-        with calibrate.solve_guard(), compile_cache.MissSnapshot() as snap:
-            stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
-                                             *states)
-            fn = _batched_dense_for(op, chosen.engine, max_rounds)
-            out, rst = fn(stacked)
-            jax.block_until_ready(out)
-        wall = time.monotonic() - t0
+        with spans.span("iwpp.engine") as engine_span, \
+                calibrate.solve_guard(), compile_cache.MissSnapshot() as snap:
+            with spans.span("iwpp.engine.prepare"):
+                stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                                 *states)
+                fn = _batched_dense_for(op, chosen.engine, max_rounds)
+            with spans.span("iwpp.engine.wait"):
+                out, rst = fn(stacked)
+                jax.block_until_ready(out)
         results = []
         for i in range(len(states)):
             st_i = SolveStats(
-                chosen.engine, rounds=int(rst.rounds[i]),
-                sources_processed=(int(rst.sources_hi[i]) << 32)
-                | int(rst.sources_lo[i]),
+                chosen.engine, rounds=spans.host_int(rst.rounds[i]),
+                sources_processed=(spans.host_int(rst.sources_hi[i]) << 32)
+                | spans.host_int(rst.sources_lo[i]),
                 recompiles=snap.count, cost_model=decided_by,
-                wall_time_s=wall, batch_size=len(states),
+                wall_time_s=engine_span.seconds, batch_size=len(states),
                 interpret=interpret)
             results.append(
                 (jax.tree_util.tree_map(lambda x: x[i], out), st_i))
