@@ -82,13 +82,21 @@ DEFAULT_DRAIN_BATCH = 4
 # draining K=4 of them per dispatch is a measured ~4-5x win on CPU hosts
 # (BENCH_tiled.json); large blocks are bandwidth-bound and the batch pays
 # max-of-batch iteration inflation plus cache pressure, so they stay
-# sequential unless the caller (or autotune) asks otherwise.  Compiled TPU grid kernels shift this
-# break-even upward — then pass drain_batch explicitly.
+# sequential unless the caller (or autotune) asks otherwise.  Compiled, the
+# Pallas grid kernels batch at every tile: `auto` offers them at K=4 too.
 BATCH_DEFAULT_MAX_TILE = 32
 
 
 def _default_drain_batch(tile: int) -> int:
     return DEFAULT_DRAIN_BATCH if tile <= BATCH_DEFAULT_MAX_TILE else 1
+
+
+# (ndim, tile) pairs whose Pallas tile kernels compile for a TPU v5e, dense
+# and batched (tests/test_tpu_compile.py compiles each; benchmarks/
+# ENGINE_GRID_v5e.json records each run on one).  Compiled, `auto`
+# offers `tiled-pallas` only at these: a 3-D 130³ block is far past VMEM,
+# and no 2-D 32-tile kernel has been compiled.
+COMPILED_PALLAS_TILES = ((2, 64), (2, 128), (3, 32))
 
 
 # ---------------------------------------------------------------------------
@@ -326,6 +334,20 @@ class CostModel:
     # Pallas interpret mode executes the kernel body in Python — only ever
     # competitive when compiled for a real TPU.
     interpret_penalty = 50.0
+    # Compiled backend only (``interpret`` False; interpreted, no cost below
+    # changes).  Fitted to warm seconds per call on a TPU v5e
+    # (benchmarks/ENGINE_GRID_v5e.json, replayed by tests/test_interpret.py).
+    # The XLA tile drain (``_xla_drain``) runs each inner iteration as
+    # several separate device ops out of HBM: a fixed cost per iteration,
+    # paid once by a vmapped batch of K blocks.
+    xla_iter_cost = 500_000.0
+    # A compiled Pallas tile drain pays a fixed cost per kernel launch (one
+    # launch drains a batch of K blocks); it is what ranks 128-tiles above
+    # 64-tiles, as the chip does.  Above ~5.9M the grid's 3-D EDT volume
+    # goes to ``frontier`` (3.5x slower there), below ~2.25M seeded morph
+    # to 64-tiles (2.1x slower); 4M keeps every pick within 30% more or
+    # fewer sources or active tiles.
+    pallas_launch_cost = 4_000_000.0
     # Queued-kernel push rounds (kernel_queue=True, DESIGN.md §2.5) touch
     # only O(queue capacity) pixels, but their gather/scatter/compaction
     # steps do not fuse the way a dense round's shifted-plane passes do, so
@@ -373,6 +395,17 @@ class CostModel:
         """
         return stats.depth_est
 
+    def _xla_drain(self, block: int, tile: int, drain_batch: int) -> float:
+        """Inner rounds of one XLA tile drain (``core.tiles.
+        _tile_local_solve``) of a ``block``-pixel block, ~``tile`` of them.
+        Interpreted, priced as VMEM-resident like the Pallas kernel's.
+        Compiled, each round reads and writes HBM (no discount) and runs as
+        several device ops: a fixed ``xla_iter_cost`` that a vmapped batch
+        of ``drain_batch`` blocks pays once."""
+        if self.interpret:
+            return block * tile * self.vmem_discount
+        return block * tile + self.xla_iter_cost * tile / max(1, drain_batch)
+
     def _drains(self, stats: InputStats, tile: int) -> float:
         """Expected tile drains: initially-active tiles, re-drained once per
         tile-layer the wavefront crosses."""
@@ -415,8 +448,10 @@ class CostModel:
             return 0.0  # dense engines are bandwidth-bound; folded above
         if e in ("tiled", "tiled-pallas"):
             block = (cfg.tile + 2) ** stats.ndim
-            inner = block * cfg.tile * self.vmem_discount
-            if e == "tiled-pallas" and cfg.kernel_queue:
+            k = max(1, cfg.drain_batch or 1)
+            if e == "tiled":
+                inner = self._xla_drain(block, cfg.tile, k)
+            elif cfg.kernel_queue:
                 from repro.kernels.ops import default_kernel_queue_capacity
                 qcap = (cfg.kernel_queue_capacity
                         or default_kernel_queue_capacity(
@@ -428,16 +463,26 @@ class CostModel:
                 inner = ((block + (self.kernel_queue_round_overhead
                                    + (stats.n_offsets + 1) * qcap) * cfg.tile)
                          * self.vmem_discount)
-            if e == "tiled-pallas" and self.interpret:
-                inner *= self.interpret_penalty
+            else:
+                inner = block * cfg.tile * self.vmem_discount
+            if e == "tiled-pallas":
+                if self.interpret:
+                    inner *= self.interpret_penalty
+                else:
+                    inner += self.pallas_launch_cost / k
             drains = self._drains(stats, cfg.tile)
-            dispatch = self.tile_dispatch / max(1, cfg.drain_batch or 1)
+            dispatch = self.tile_dispatch / k
             return drains * inner + drains * dispatch
         if e == "scheduler":
             block = (cfg.tile + 2) ** stats.ndim
             drains = self._drains(stats, cfg.tile)
-            return (drains * block * cfg.tile * self.vmem_discount
-                    * self.host_penalty + drains * self.host_dispatch)
+            if self.interpret:
+                return (drains * block * cfg.tile * self.vmem_discount
+                        * self.host_penalty + drains * self.host_dispatch)
+            # Compiled, each host task is one XLA tile drain on the device
+            # (_host_tile_fn_for jits it for the default backend).
+            return drains * (self._xla_drain(block, cfg.tile, 1)
+                             + self.host_dispatch)
         if e == "hybrid":
             # Cooperative pool: host threads and the batched device stream
             # consume one queue, so throughputs *add* (harmonic combination
@@ -446,8 +491,17 @@ class CostModel:
             # conservative O(area) charge for the pass's host-side overhead
             # (padded-state copies, and the BP recovery probe when a pass
             # loses its workers).
-            host_unit, dev_unit = self._hybrid_units(cfg.tile,
-                                                     cfg.drain_batch or 1)
+            k = cfg.drain_batch or 1
+            if self.interpret:
+                host_unit, dev_unit = self._hybrid_units(cfg.tile, k)
+            else:
+                # Compiled, both worker kinds run the XLA tile drain on the
+                # device: a host thread one block per dispatch, the stream k.
+                block = (cfg.tile + 2) ** stats.ndim
+                host_unit = (self._xla_drain(block, cfg.tile, 1)
+                             + self.host_dispatch)
+                dev_unit = (self._xla_drain(block, cfg.tile, k)
+                            + self.tile_dispatch / k)
             drains = self._drains(stats, cfg.tile)
             rate = self.hybrid_host_workers / host_unit + 1.0 / dev_unit
             return drains / rate + stats.area
@@ -458,9 +512,10 @@ class CostModel:
             # devices worth of drains each) + the same per-BP-round
             # collective latency as the flat shard_map.
             block = (cfg.tile + 2) ** stats.ndim
-            inner = block * cfg.tile * self.vmem_discount
+            k = max(1, cfg.drain_batch or 1)
+            inner = self._xla_drain(block, cfg.tile, k)
             drains = self._drains(stats, cfg.tile) / stats.n_devices
-            dispatch = self.tile_dispatch / max(1, cfg.drain_batch or 1)
+            dispatch = self.tile_dispatch / k
             return (drains * (inner + dispatch)
                     + self._bp_rounds(stats) * self.collective_latency
                     * stats.n_devices)
@@ -551,10 +606,16 @@ class CostModel:
             cap = min(max(4, stats.n_tiles(t)), 256)
             db = min(cap, _default_drain_batch(t))
             out.append(EngineConfig("tiled", t, cap, db))
-            out.append(EngineConfig("tiled-pallas", t, cap, db))
-            if self.interpret:  # queued kernels do not compile yet
+            if self.interpret:
+                out.append(EngineConfig("tiled-pallas", t, cap, db))
+                # queued kernels do not compile yet
                 out.append(EngineConfig("tiled-pallas", t, cap, db,
                                         kernel_queue=True))
+            elif (stats.ndim, t) in COMPILED_PALLAS_TILES:
+                # A compiled batched kernel drains K blocks per launch at
+                # any tile, so the batch competes at every compiled tile.
+                for k in sorted({db, min(cap, DEFAULT_DRAIN_BATCH)}):
+                    out.append(EngineConfig("tiled-pallas", t, cap, k))
             out.append(EngineConfig("scheduler", t, cap))
             out.append(EngineConfig("hybrid", t, cap, db))
             if stats.n_devices > 1:

@@ -20,6 +20,7 @@ from repro.kernels.edt_tile import edt_tile_solve_batched_nd, edt_tile_solve_nd
 from repro.kernels.morph_tile import morph_tile_solve, morph_tile_solve_batched
 from repro.kernels.ops import (raster_pass_kernel, tile_solver_label,
                                tile_solver_label_batched)
+from repro.solve import COMPILED_PALLAS_TILES
 
 
 @pytest.fixture(scope="module")
@@ -78,20 +79,23 @@ def _label(conn, bound, batched):
             lambda blk: ((blk, I32), (blk, BOOL), (blk, BOOL)))
 
 
-# (kernel family, spatial rank, tile, batch K or None)
-CASES = [(fam, 2, t, k) for fam in ("morph", "edt", "label")
-         for t in (64, 128) for k in (None, 8 if fam == "edt" else 4)]
-CASES += [(fam, 3, 32, k) for fam in ("morph", "edt") for k in (None, 4)]
+# (kernel family, spatial rank, tile, batch K or None): every tile at which
+# a compiled `auto` may run `tiled-pallas`, for each op's default
+# neighbourhood ("fill" = fill_holes' 4-connected flood on the morph kernel)
+FAMILIES = {2: ("morph", "edt", "label", "fill"), 3: ("morph", "edt")}
+CASES = [(fam, nd, t, k) for nd, t in COMPILED_PALLAS_TILES
+         for fam in FAMILIES[nd]
+         for k in (None, 8 if fam == "edt" and nd == 2 else 4)]
 
 
 @pytest.mark.parametrize("family,ndim,tile,batch", CASES,
                          ids=[f"{f}-{n}d-T{t}-{'K%d' % k if k else 'dense'}"
                               for f, n, t, k in CASES])
 def test_tile_kernel_compiles_for_v5e(one_chip, family, ndim, tile, batch):
-    conn = 8 if ndim == 2 else "conn26"
+    conn = 4 if family == "fill" else 8 if ndim == 2 else "conn26"
     bound = (tile + 2) ** ndim           # the tiled engine's geodesic bound
-    fn, shapes = {"morph": _morph, "edt": _edt, "label": _label}[family](
-        conn, bound, batch is not None)
+    fn, shapes = {"morph": _morph, "edt": _edt, "label": _label,
+                  "fill": _morph}[family](conn, bound, batch is not None)
     blk = ((batch,) if batch else ()) + (tile + 2,) * ndim
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes(blk)]
