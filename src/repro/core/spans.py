@@ -7,8 +7,9 @@ session shows it on the host thread on the device trace's own clock, and an
 entry ``(name, parent index, start ns, end ns)`` on ``time.monotonic_ns``
 in the calling thread's open record.  The outermost span of a thread opens
 the record and closes it; :func:`recorded` hands the closed record to the
-call's ``SolveStats`` (``spans``, ``host_syncs``).  :func:`host_int` is
-the device -> host read of that path, counted in the open record.
+call's ``SolveStats`` (``spans``, ``host_syncs``).  :func:`host_int` and
+:func:`host_ints` are the device -> host reads of that path, each counted
+once in the open record.
 
 Spans go at call-level boundaries only, never inside a per-round, per-tile
 or per-worker loop; work on other threads (the scheduler's workers) is not
@@ -21,8 +22,9 @@ import dataclasses
 import functools
 import threading
 import time
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
+import jax
 from jax.profiler import TraceAnnotation
 
 _local = threading.local()
@@ -74,12 +76,23 @@ class span:
         return (self.end_ns - self.start_ns) / 1e9
 
 
-def host_int(x) -> int:
-    """``int(x)`` of a device value: a device -> host read, counted."""
+def _count_sync() -> None:
     rec = getattr(_local, "record", None)
     if rec is not None:
         rec.host_syncs += 1
+
+
+def host_int(x) -> int:
+    """``int(x)`` of a device value: a device -> host read, counted."""
+    _count_sync()
     return int(x)
+
+
+def host_ints(x) -> Tuple[int, ...]:
+    """The ints of a device vector in one device -> host read, counted
+    once."""
+    _count_sync()
+    return tuple(int(v) for v in jax.device_get(x))
 
 
 def _attach(stats, root: span):

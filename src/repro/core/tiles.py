@@ -180,14 +180,24 @@ def active_tiles_from_frontier(op: PropagationOp, frontier, tile: int,
     `shard_map-tiled` engine: each BP round seeds the per-device queue with
     exactly the tiles the halo exchange improved (core/distributed.py).
     """
-    ndim = op.ndim
-    spatial = frontier.shape[-ndim:]
-    if grid is None:
-        grid = tuple(-(-s // tile) for s in spatial)
+    return tiles_any(dilate_frontier(op, frontier), tile, grid)
+
+
+def dilate_frontier(op: PropagationOp, frontier):
+    """``frontier`` or'ed with its shifts by each of the op's offsets."""
     dil = frontier
     for off in op.offsets:
         dil = dil | shiftnd(frontier, off, False)
-    fp = jnp.pad(dil, [(0, g * tile - s) for g, s in zip(grid, spatial)])
+    return dil
+
+
+def tiles_any(mask, tile: int, grid: Optional[Tuple[int, ...]] = None):
+    """Per-tile ``any`` of a spatial bool ``mask``, zero-padded up to whole
+    ``tile``-sided tiles (``grid`` tiles per axis)."""
+    ndim = mask.ndim
+    if grid is None:
+        grid = tuple(-(-s // tile) for s in mask.shape)
+    fp = jnp.pad(mask, [(0, g * tile - s) for g, s in zip(grid, mask.shape)])
     inter = []
     for g in grid:
         inter += [g, tile]

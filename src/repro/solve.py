@@ -259,16 +259,35 @@ class InputStats:
         return math.prod(-(-s // tile) for s in self.spatial)
 
 
+def _input_probe_for(op: PropagationOp, tiles: Tuple[int, ...]):
+    """The selection probes as one jitted program: ``state -> int32[1 +
+    len(tiles)]``, the initial frontier's population and then, for each
+    tile size, the tiles it activates (:func:`initial_active_tiles`).  The
+    frontier is built and dilated once, whatever the number of tiles."""
+    def build():
+        @jax.jit
+        def probe(state):
+            f0 = op.init_frontier(state)
+            dil = _tiles.dilate_frontier(op, f0)
+            return jnp.stack(
+                [jnp.sum(f0, dtype=jnp.int32)]
+                + [jnp.sum(_tiles.tiles_any(dil, t), dtype=jnp.int32)
+                   for t in tiles])
+        return probe
+
+    return compile_cache.get(
+        ("input-probe", type(op), op.connectivity, op.ndim, tiles), build)
+
+
 def collect_input_stats(op: PropagationOp, state, n_devices: int = 1,
                         tiles: Sequence[int] = DEFAULT_TILES) -> InputStats:
     spatial = tree_shape(state, op.ndim)
     H, W = spatial[-2:]
+    tiles = tuple(tiles)
     with spans.span("iwpp.select.input_stats"):
-        f0 = op.init_frontier(state)
-        n_sources = spans.host_int(jnp.sum(f0))
-        active = {t: spans.host_int(
-                      jnp.sum(initial_active_tiles(op, state, t)))
-                  for t in tiles}
+        n_sources, *counts = spans.host_ints(
+            _input_probe_for(op, tiles)(state))
+    active = dict(zip(tiles, counts))
     spec = spec_for(op)
     return InputStats(H, W, n_sources, active, n_devices,
                       bytes_per_pixel=spec.bytes_per_pixel if spec else 4.0,

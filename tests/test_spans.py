@@ -55,13 +55,13 @@ EXPLICIT_TILED = [("iwpp.run_op", -1), ("iwpp.build_state", 0),
                   ("iwpp.engine.prepare", 3), ("iwpp.engine.wait", 3),
                   ("iwpp.engine.wait", 3), ("iwpp.extract", 0)]
 
-# (solve keywords, span tree, host syncs): 4 reads of the selection probes
-# (the frontier population, the active tiles at each of 3 tile sizes); the
-# dense adapter reads rounds and the two words of the source count, the
-# tiled one its four counters.
+# (solve keywords, span tree, host syncs): 1 read of the selection probe
+# (the frontier population and the active tiles at each of 3 tile sizes, in
+# one vector); the dense adapter reads rounds and the two words of the
+# source count, the tiled one its four counters.
 CASES = {
-    "auto-frontier": (dict(cost_model=Prefer("frontier")), AUTO_FRONTIER, 7),
-    "auto-tiled": (dict(cost_model=Prefer("tiled")), AUTO_TILED, 8),
+    "auto-frontier": (dict(cost_model=Prefer("frontier")), AUTO_FRONTIER, 4),
+    "auto-tiled": (dict(cost_model=Prefer("tiled")), AUTO_TILED, 5),
     "explicit-tiled": (dict(engine="tiled"), EXPLICIT_TILED, 4),
 }
 
@@ -161,7 +161,7 @@ def test_engine_that_raises_leaves_no_span_open(monkeypatch):
     assert getattr(spans._local, "record", None) is None
     _, st = run_op("morph", marker, mask, connectivity=8,
                    cost_model=Prefer("frontier"))
-    assert _tree(st) == AUTO_FRONTIER and st.host_syncs == 7
+    assert _tree(st) == AUTO_FRONTIER and st.host_syncs == 4
     _assert_nested(st)
 
 
